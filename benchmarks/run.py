@@ -247,6 +247,8 @@ def main(argv=None) -> None:
                          "BENCH_BASELINE.json (nonzero exit on "
                          "regression)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     print("name,us_per_call,derived")
     failed = []
     module_results = {}

@@ -92,23 +92,13 @@ def stack_stages(blocks: PyTree, stage_of: list[int], n_stages: int):
 
 def _shard_map_stage(fn: Callable, mesh, in_specs, out_specs,
                      stage_axis, extra_axes: tuple = ()) -> Callable:
-    """Version-compat shard_map over the stage axis (plus any
-    ``extra_axes`` that are also manual — the data axis of a 2-D
-    stage x data pipeline); remaining mesh axes stay auto/replicated
-    per the specs."""
+    """shard_map over the stage axis (plus any ``extra_axes`` that are
+    also manual — the data axis of a 2-D stage x data pipeline);
+    remaining mesh axes stay auto/replicated per the specs."""
     manual = frozenset({stage_axis, *extra_axes})
-    if hasattr(jax, "shard_map"):             # jax >= 0.6
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-            axis_names=manual)                # other mesh axes stay auto
-    # 0.4.x experimental API. Full manual: partial-auto lowers axis_index
-    # to a PartitionId op the XLA:CPU SPMD partitioner rejects. Non-stage
-    # axes are replicated per the specs (costs an all-gather of the
-    # input on multi-axis meshes; prefer the gspmd paths there).
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False,
+                         axis_names=manual)
 
 
 def make_stage_fn(block_fn: Callable) -> Callable:
@@ -530,7 +520,7 @@ def _check_hetero_params(stage_fns, n_stages, stage_params, mesh,
         raise ValueError(
             "per-stage weight placement (stage_params=...) requires a "
             f"mesh with a {stage_axis!r} axis to place each stage's "
-            f"weights onto, got {have}; pass mesh=jax.make_mesh"
+            f"weights onto, got {have}; pass mesh=launch.mesh.make_mesh"
             f"(({n_stages},), ({stage_axis!r},)), drop stage_params "
             "to run with replicated params, or pass "
             "PlacedParams.pack_ragged() rows for single-host packed "

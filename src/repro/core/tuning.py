@@ -168,7 +168,7 @@ def device_signature() -> str:
     from repro.kernels import ops as kops
     d = jax.devices()[0]
     kind = str(getattr(d, "device_kind", None) or d.platform)
-    return f"{kind.lower().replace(' ', '-')}:{kops._IMPL}"
+    return f"{kind.lower().replace(' ', '-')}:{kops.impl()}"
 
 
 def _shp(shape) -> str:
@@ -400,6 +400,7 @@ def autotune_depthwise_block_c(x, w, *, stride: int = 1,
     feasibility rule — every candidate respects the 8MB budget by
     construction), record the winner."""
     from repro.kernels import depthwise_conv as dw
+    from repro.kernels import ops as kops
     c = x.shape[-1]
     cands = dw.block_c_candidates(x.shape[2], c, w.shape[1], stride,
                                   np.dtype(x.dtype).itemsize)
@@ -408,7 +409,8 @@ def autotune_depthwise_block_c(x, w, *, stride: int = 1,
     import jax
     for tc in cands:
         fn = jax.jit(lambda a, _tc=tc: dw.depthwise_conv_pallas(
-            a, w, stride=stride, block_c=_tc))
+            a, w, stride=stride, block_c=_tc,
+            interpret=kops.interpret_mode()))
         us = _time_call(fn, x, warmup=1, iters=iters)
         if us < best_us:
             best, best_us = tc, us
@@ -446,6 +448,7 @@ def autotune_sparse_conv_block_k(x, sw, bias, *, k: int, stride: int = 1,
     """Search the sparse-conv Pallas kernel's K-tile (how many weight
     blocks each grid step gathers+accumulates) over the divisors of the
     node's kept-block count."""
+    from repro.kernels import ops as kops
     from repro.kernels import sparse_conv as sc
     import jax
     n_k = sw.vals.shape[1]
@@ -457,7 +460,7 @@ def autotune_sparse_conv_block_k(x, sw, bias, *, k: int, stride: int = 1,
     for t in cands:
         fn = jax.jit(lambda a, _t=t: sc.sparse_conv_pallas(
             a, sw.vals, sw.idx, bias, k=k, stride=stride, relu=relu,
-            block_k=_t))
+            block_k=_t, interpret=kops.interpret_mode()))
         us = _time_call(fn, x, warmup=1, iters=iters)
         if us < best_us:
             best, best_us = t, us
@@ -518,11 +521,11 @@ def autotune_graph(cfg, params, image_shape, *, graph=None,
         if sig in seen:
             continue
         seen.add(sig)
-        if node.kind == "dw" and kops._IMPL == "pallas":
+        if node.kind == "dw" and kops.impl() == "pallas":
             p = params[node.name]
             best = autotune_depthwise_block_c(
                 x, p["w"], stride=node.stride, cache=cache, iters=iters)
-        elif node.kind == "dw_pw" and kops._IMPL == "xla":
+        elif node.kind == "dw_pw" and kops.impl() == "xla":
             dw_p = params[node.parts[0].name]
             pw_p = params[conv_part(node).name]
             if isinstance(pw_p["w"], SparseWeight):
@@ -530,7 +533,7 @@ def autotune_graph(cfg, params, image_shape, *, graph=None,
             best = autotune_dw_pw_row_chunk(
                 x, dw_p["w"], dw_p["b"], pw_p["w"], pw_p["b"],
                 stride=node.stride, cache=cache, iters=iters)
-        elif node.kind == "conv" and kops._IMPL == "pallas":
+        elif node.kind == "conv" and kops.impl() == "pallas":
             p = params[conv_part(node).name]
             if not isinstance(p["w"], SparseWeight):
                 continue

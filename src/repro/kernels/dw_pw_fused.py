@@ -14,7 +14,8 @@ TPU mapping (mirrors kernels/sparse_conv.py):
 
 - line buffer -> one padded input row (1, 1, Wp, C) resident in VMEM;
   the ky shift is folded into the HBM row address by the index map
-  (H-block size 1 => absolute row), the kx shift is an in-VMEM slice;
+  (H-block size 1 => absolute row), the kx shift is a strided window
+  read of the row;
 - depthwise unit -> f32 (Wo, C) VPU accumulator revisited across the k
   innermost grid steps (shifted multiply-accumulate, no channel
   reduction);
@@ -47,8 +48,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.depthwise_conv import shifted_row_mac
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels.depthwise_conv import LANES, shifted_row_mac
 from repro.kernels.sparse_conv import pad_same_hw
 
 
@@ -59,16 +59,16 @@ def _kernel(x_ref, dww_ref, dwb_ref, pww_ref, pwb_ref, *rest,
     if has_scale:
         scale_ref, rest = rest[0], rest[1:]
     if has_res:
-        res_ref, o_ref, acc_ref = rest
+        res_ref, o_ref, acc_ref, row_ref = rest
     else:
-        o_ref, acc_ref = rest
+        o_ref, acc_ref, row_ref = rest
     ky = pl.program_id(2)
 
     @pl.when(ky == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += shifted_row_mac(x_ref[0, 0], dww_ref[ky], k, wo, stride)
+    shifted_row_mac(x_ref, row_ref, acc_ref, dww_ref[ky], k, wo, stride)
 
     @pl.when(ky == k - 1)
     def _flush():
@@ -79,7 +79,8 @@ def _kernel(x_ref, dww_ref, dwb_ref, pww_ref, pwb_ref, *rest,
         # the unfused graph's node boundary does — but in VMEM, not HBM
         d = d.astype(out_dtype)
         y = jnp.dot(d.astype(jnp.float32), pww_ref[...].astype(jnp.float32),
-                    preferred_element_type=jnp.float32)
+                    preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST)
         if has_scale:
             # int8 pw: y holds the raw code dot — the per-channel scale
             # re-reals it before the (real-valued) bias joins
@@ -99,17 +100,19 @@ def dw_pw_pallas(x: jax.Array, dw_w: jax.Array, dw_b: jax.Array,
                  residual: jax.Array = None,
                  pw_scale: jax.Array = None, *, stride: int = 1,
                  dw_relu: bool = True, relu: bool = True,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool) -> jax.Array:
     """x: (N, H, W, C); dw_w: (k, k, C); dw_b: (C,); pw_w: (C, Cout);
     pw_b: (Cout,); residual: optional (N, Ho, Wo, Cout) fused skip.
     ``pw_scale`` (optional, (Cout,) f32) marks ``pw_w`` as int8 codes
     (core/quant.py): the MXU dot is unchanged and the scale multiplies
     its output at the flush, before pw bias. SAME padding on the
-    depthwise. Returns (N, Ho, Wo, Cout)."""
+    depthwise. Returns (N, Ho, Wo, Cout). ``interpret`` runs the kernel
+    body in the Pallas interpreter (CPU) instead of Mosaic (TPU);
+    ``kernels.ops`` picks it from the platform."""
     n, h, w, c = x.shape
     k = dw_w.shape[0]
     co = pw_w.shape[-1]
-    xp, ho, wo = pad_same_hw(x, k, stride, overread=True)
+    xp, ho, wo = pad_same_hw(x, k, stride)
     wp = xp.shape[2]
 
     has_res = residual is not None
@@ -141,10 +144,12 @@ def dw_pw_pallas(x: jax.Array, dw_w: jax.Array, dw_b: jax.Array,
         out_specs=pl.BlockSpec((1, 1, wo, co),
                                lambda i, oy, ky: (i, oy, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, ho, wo, co), x.dtype),
-        scratch_shapes=[pltpu.VMEM((wo, c), jnp.float32)],
-        compiler_params=CompilerParams(
+        scratch_shapes=[pltpu.VMEM((wo, c), jnp.float32),
+                        pltpu.VMEM((wp, min(c, LANES)), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="dw_pw",
     )(*operands)
 
 

@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -74,8 +72,11 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 @functools.partial(jax.jit, static_argnames=("causal", "window", "q_offset",
                                              "block_q", "block_k", "interpret"))
 def flash_attention_pallas(q, k, v, *, causal=True, window=0, q_offset=0,
-                           block_q=128, block_k=128, interpret=True):
-    """q: (B, Tq, H, D); k, v: (B, Tk, H, D) (GQA pre-expanded)."""
+                           block_q=128, block_k=128, interpret: bool):
+    """q: (B, Tq, H, D); k, v: (B, Tk, H, D) (GQA pre-expanded).
+    ``interpret`` runs the kernel body in the Pallas interpreter (CPU)
+    instead of Mosaic (TPU); ``kernels.ops`` picks it from the
+    platform."""
     b, tq, h, d = q.shape
     tk = k.shape[1]
     block_q = min(block_q, tq)
@@ -104,8 +105,9 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=0, q_offset=0,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention",
     )(qf, kf, vf)
     return out.reshape(b, h, tq, d).transpose(0, 2, 1, 3)

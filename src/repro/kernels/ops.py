@@ -2,22 +2,42 @@
 
 Two execution paths per op:
 - ``xla``: pure-JAX formulation with the same zero-block skipping
-  semantics; shards cleanly under pjit/GSPMD and is the path used by the
-  full-scale dry-run (Pallas cannot target the CPU backend non-
-  interpreted, see DESIGN.md §2).
-- ``pallas``: the TPU kernel (validated in interpret mode on CPU).
+  semantics; shards cleanly under pjit/GSPMD, runs on every backend,
+  and is the reference twin the Pallas path is checked against.
+- ``pallas``: the HPIPE kernels (kernels/*.py).
+
+The path is chosen by platform (DESIGN.md §2): Mosaic-compiled Pallas
+on ``tpu``, the XLA twins on ``cpu``. Any other platform raises. The
+``REPRO_KERNEL_IMPL`` environment variable, ``set_impl`` and
+``config(impl=)`` override the choice (tests drive the Pallas path on
+the CPU that way, and the chip smoke runs the XLA twin on the TPU as
+its reference); an unknown value raises. The Pallas kernels run in
+the interpreter exactly when the platform is ``cpu``.
 """
 from __future__ import annotations
 
 import contextlib
 import os
-from typing import Literal, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-_IMPL: Literal["xla", "pallas"] = os.environ.get("REPRO_KERNEL_IMPL", "xla")
+IMPLS = ("xla", "pallas")
+
+#: platform -> (default impl, whether Pallas kernels run interpreted)
+_PLATFORMS = {"tpu": ("pallas", False), "cpu": ("xla", True)}
+
+
+def _check_impl(impl):
+    if impl is not None and impl not in IMPLS:
+        raise ValueError(f"kernel impl {impl!r}: expected one of {IMPLS}")
+    return impl
+
+
+# explicit override of the platform's choice (None: choose by platform)
+_IMPL: Optional[str] = _check_impl(os.environ.get("REPRO_KERNEL_IMPL"))
 
 # int8 execution strategy for quantized weights (core/quant.py). True:
 # the codes feed the matmul unmodified (int8 MXU operands, f32
@@ -27,12 +47,25 @@ _IMPL: Literal["xla", "pallas"] = os.environ.get("REPRO_KERNEL_IMPL", "xla")
 # fast path is tested against).
 _INT8_FAST = True
 
-# Perf-iteration knob: token-shard the sparse-matmul input so block
-# gathers stay shard-local (see the sharding note inside sparse_matmul).
-# REFUTED at TP=16 — vals are ob-sharded on the same axis, so GSPMD
-# gathers the 2.5GB weight stack per layer instead (27s -> 65s
-# collective). Kept, default-off, for meshes with a spare axis.
-_SPARSE_X_TOKEN_SHARD = False
+
+def _platform() -> tuple[str, bool]:
+    p = jax.default_backend()
+    if p not in _PLATFORMS:
+        raise RuntimeError(f"no kernel path for platform {p!r}: expected "
+                           f"one of {sorted(_PLATFORMS)}")
+    return _PLATFORMS[p]
+
+
+def impl() -> str:
+    """The kernel path ops dispatch to: the override if one is set,
+    else the platform's default. Read at TRACE time."""
+    return _IMPL or _platform()[0]
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in the interpreter (cpu) rather than
+    compiled by Mosaic (tpu)."""
+    return _platform()[1]
 
 
 class _ImplGuard:
@@ -60,9 +93,8 @@ def set_impl(impl: str) -> _ImplGuard:
     """Set the kernel dispatch path. Usable bare (``set_impl("xla")``)
     or as a context manager that restores the prior impl on exit."""
     global _IMPL
-    assert impl in ("xla", "pallas"), impl
     prev = _IMPL
-    _IMPL = impl
+    _IMPL = _check_impl(impl)
     return _ImplGuard(prev)
 
 
@@ -94,8 +126,7 @@ def config(*, impl: Optional[str] = None, tuning_cache=None,
     cache_guard = None
     try:
         if impl is not None:
-            assert impl in ("xla", "pallas"), impl
-            _IMPL = impl
+            _IMPL = _check_impl(impl)
         if int8_fast_path is not None:
             _INT8_FAST = bool(int8_fast_path)
         if tuning_cache is not None:
@@ -131,12 +162,11 @@ def sparse_matmul(x: jax.Array, sw) -> jax.Array:
     scale = sw.scale                    # (ob, bn) f32 or None
     *lead, d_in = x.shape
     ob, n_k, bm, bn = sw.vals.shape
-    if _IMPL == "pallas":
+    if impl() == "pallas":
         xm = x.reshape(-1, d_in)
-        m = xm.shape[0]
-        tm = 128 if m % 128 == 0 else (8 if m % 8 == 0 else 1)
         from repro.kernels.sparse_matmul import sparse_matmul_pallas
-        out = sparse_matmul_pallas(xm, sw.vals, sw.idx, block_m_x=tm)
+        out = sparse_matmul_pallas(xm, sw.vals, sw.idx,
+                                   interpret=interpret_mode())
         if scale is not None:
             # no fused bias in this kernel, so the epilogue scale is
             # safe outside it: out still carries the raw code dot
@@ -148,26 +178,6 @@ def sparse_matmul(x: jax.Array, sw) -> jax.Array:
     # Each step gathers one input block per output column (working set ==
     # output size, never the KxM blowup a naive take would produce) and
     # accumulates in f32 — gather-not-scatter, as in the paper.
-    #
-    # Sharding: the gather indexes the FEATURE axis, so under GSPMD a
-    # feature-sharded input forces an all-gather of x per layer (the
-    # dominant collective in the baseline roofline). Constraining x to
-    # TOKEN-sharded ("model" on the flattened token axis) makes every
-    # block gather shard-local; the reshard is a ~1/TP-size all-to-all.
-    from repro.models import lm as _lm
-    mesh = _lm._BOUNDARY.get("mesh") if _SPARSE_X_TOKEN_SHARD else None
-    if mesh is not None and x.ndim >= 2:
-        from jax.sharding import PartitionSpec as P
-        sizes = dict(mesh.shape)
-        tok = 1
-        for dim in x.shape[:-1]:
-            tok *= dim
-        spec = [None] * x.ndim
-        if x.shape[0] % sizes.get("data", 1) == 0 and                 x.shape[0] >= sizes.get("data", 1):
-            spec[0] = "data"
-        if x.ndim >= 3 and x.shape[1] % sizes.get("model", 1) == 0 and                 x.shape[1] >= sizes.get("model", 1):
-            spec[1] = "model"
-        x = jax.lax.with_sharding_constraint(x, P(*spec))
     xb = x.reshape(-1, d_in // bm, bm)
     t = xb.shape[0]
 
@@ -215,7 +225,7 @@ def sparse_conv(x, sw, bias, *, k: int, stride: int = 1,
     ob, n_k, bm, bn = sw.vals.shape
     assert sw.d_in == k * k * c, (sw.d_in, k, c)
     assert c % bm == 0, (c, bm)
-    if _IMPL == "pallas":
+    if impl() == "pallas":
         from repro.kernels.sparse_conv import sparse_conv_pallas
         bk = _knob("sconv", x.shape, x.dtype, "block_k", 1,
                    k=k, s=stride, b=f"{bm}x{bn}K{n_k}", co=ob * bn)
@@ -223,7 +233,7 @@ def sparse_conv(x, sw, bias, *, k: int, stride: int = 1,
             bk = 1
         return sparse_conv_pallas(x, sw.vals, sw.idx, bias, residual,
                                   scale, k=k, stride=stride, relu=relu,
-                                  block_k=bk)
+                                  block_k=bk, interpret=interpret_mode())
 
     # XLA path: lax.scan over the K surviving blocks per output column.
     # Each step gathers one shifted (ky, kx) window slice of the
@@ -278,10 +288,11 @@ def sparse_conv(x, sw, bias, *, k: int, stride: int = 1,
 
 def flash_attention(q, k, v, *, causal=True, window=0, q_offset=0):
     """Dispatch: Pallas flash kernel (TPU target) or blockwise XLA."""
-    if _IMPL == "pallas":
+    if impl() == "pallas":
         from repro.kernels.flash_attention import flash_attention_pallas
         return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                      q_offset=q_offset)
+                                      q_offset=q_offset,
+                                      interpret=interpret_mode())
     from repro.models.layers import blockwise_attention
     return blockwise_attention(q, k, v, causal=causal, window=window,
                                q_offset=q_offset)
@@ -289,7 +300,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, q_offset=0):
 
 def depthwise_conv(x, w, *, stride: int = 1):
     """NHWC depthwise conv dispatch (HPIPE's DepthwiseConv2D unit)."""
-    if _IMPL == "pallas":
+    if impl() == "pallas":
         from repro.kernels.depthwise_conv import depthwise_conv_pallas
         # block_c=0: the kernel clamps the channel tile to its VMEM
         # budget (the 112x112 MobileNet layers used to overflow at 128);
@@ -298,7 +309,8 @@ def depthwise_conv(x, w, *, stride: int = 1):
                    k=w.shape[0], s=stride)
         if tc and x.shape[-1] % tc:     # stale cache entry: C changed
             tc = 0
-        return depthwise_conv_pallas(x, w, stride=stride, block_c=tc)
+        return depthwise_conv_pallas(x, w, stride=stride, block_c=tc,
+                                     interpret=interpret_mode())
     from repro.kernels.depthwise_conv import depthwise_conv_ref
     return depthwise_conv_ref(x, w, stride=stride)
 
@@ -326,10 +338,11 @@ def dw_pw_conv(x, dw_w, dw_b, pw_w, pw_b, *, stride: int = 1,
             pw_scale, pw_w = pw_w.scale, pw_w.codes      # (Cout,) f32
         else:
             pw_w = pw_w.dequant()       # reference path: dequant at entry
-    if _IMPL == "pallas":
+    if impl() == "pallas":
         from repro.kernels.dw_pw_fused import dw_pw_pallas
         return dw_pw_pallas(x, dw_w, dw_b, pw_w, pw_b, residual, pw_scale,
-                            stride=stride, dw_relu=dw_relu, relu=relu)
+                            stride=stride, dw_relu=dw_relu, relu=relu,
+                            interpret=interpret_mode())
     from repro.kernels.dw_pw_fused import dw_pw_xla
     hb = _knob("dwpw", x.shape, x.dtype, "row_chunk", 0,
                k=dw_w.shape[1], s=stride, co=pw_w.shape[-1])

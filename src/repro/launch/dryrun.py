@@ -221,8 +221,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     t0 = time.time()
     from repro.launch import shardings as shl
     pure_dp = shl.use_pure_dp(cfg)
-    # jax.set_mesh is 0.5+; on 0.4.x the Mesh itself is the context
-    with (jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh):
+    with jax.set_mesh(mesh):
         bspec = (P(("data", "model"), None, None) if pure_dp
                  else P("data", "model", None))
         lmlib.set_boundary_spec(None if shape.kind == "decode" else bspec,
@@ -419,8 +418,6 @@ def run_cnn_pipeline_cell(arch: str, *, n_stages: int = 4,
     t1 = time.time()
     coll = collective_bytes(compiled.as_text())
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):       # 0.4.x: one dict per partition
-        cost = cost[0] if cost else {}
     res = {
         "arch": arch, "shape": "pipeline_cnn", "status": "ok",
         "mesh": (f"{r}x{s}(data,stage)" if r > 1 else f"{s}x1(stage)"),

@@ -3,31 +3,46 @@ dry-run). A function, not a module constant — importing this module must
 never touch jax device state."""
 from __future__ import annotations
 
+import contextlib
+
 import jax
+import numpy as np
+from jax.sharding import AxisType, Mesh
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``. JAX makes Explicit
+    axes by default, and under them the pipeline executors' indexing of
+    stage-sharded values (``outs_all[-1]``) is not implemented; every
+    mesh in this repo is built here so the rule lives in one place.
+    ``devices``: explicit device list, laid out in exactly that order
+    (default: ``jax.make_mesh``'s choice among ``jax.devices()``)."""
+    types = (AxisType.Auto,) * len(axes)
+    if devices is not None:
+        return Mesh(np.asarray(devices).reshape(shape), tuple(axes),
+                    axis_types=types)
+    return jax.make_mesh(tuple(shape), tuple(axes), axis_types=types)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(*, n_data: int = 2, n_model: int = 2, n_pod: int = 0):
     """Small mesh for CPU tests (requires forced host device count)."""
     if n_pod:
-        return jax.make_mesh((n_pod, n_data, n_model),
-                             ("pod", "data", "model"))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+        return make_mesh((n_pod, n_data, n_model), ("pod", "data", "model"))
+    return make_mesh((n_data, n_model), ("data", "model"))
 
 
 def mesh_context(mesh):
-    """Fresh mesh context per use: ``jax.set_mesh`` on 0.5+, the Mesh
-    itself as context on 0.4.x, a no-op without a mesh. One helper so
-    the version-compat rule lives in one place (serve + dryrun)."""
-    import contextlib
+    """Fresh ``jax.set_mesh`` context per use, a no-op without a mesh
+    (serve + dryrun + the serving tier)."""
     if mesh is None:
         return contextlib.nullcontext()
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
+    return jax.set_mesh(mesh)
 
 
 def make_stage_mesh(n_stages: int, n_replicas: int = 1, *,
@@ -45,8 +60,6 @@ def make_stage_mesh(n_stages: int, n_replicas: int = 1, *,
     host pool, so two workers never share a stage slot). Must hold
     exactly ``n_stages * n_replicas`` devices; default: the first
     ``n_stages * n_replicas`` of ``jax.devices()``."""
-    import numpy as np
-    from jax.sharding import Mesh
     shape = (n_replicas, n_stages) if n_replicas > 1 else (n_stages,)
     axes = (data_axis, stage_axis) if n_replicas > 1 else (stage_axis,)
     if devices is not None:
@@ -55,8 +68,7 @@ def make_stage_mesh(n_stages: int, n_replicas: int = 1, *,
             raise ValueError(f"stage mesh needs exactly {need} devices "
                              f"({n_stages} stages x {n_replicas} "
                              f"replicas), got {len(devices)}")
-        return Mesh(np.asarray(devices).reshape(shape), axes)
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes, devices=devices)
 
 
 # TPU v5e hardware constants for the roofline analysis

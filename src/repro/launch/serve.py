@@ -313,9 +313,10 @@ def _serve_cnn(arch: str, *, batch: int = 16, n_microbatches: int = 4,
     (``pipeline_cnn`` mode).
 
     Plans cost-balanced stage cuts over the layer-graph IR
-    (planner.plan_cnn_pipeline, cycle estimates from the pruned
-    weights), compiles per-stage wire programs, and streams
-    microbatches through the GSPMD pipeline executor.
+    (planner.plan, cycle estimates from the pruned weights), compiles
+    per-stage wire programs, and streams microbatches through the
+    GSPMD pipeline executor on one device, or the shard_map executor
+    when the stages are placed on their own devices.
 
     Weight placement: with one device per stage available, each stage's
     param slice is packed and ``jax.device_put`` onto ONLY that stage's
@@ -378,18 +379,14 @@ def _serve_cnn(arch: str, *, batch: int = 16, n_microbatches: int = 4,
         run_args = (x_mb, jax.device_put(pparams.pack(), sps["buffer"]))
 
         def pipeline(wires, pb):
-            if r > 1:
-                # shard_map: every device runs literally the
-                # single-pipeline program (lax.switch + in-replica
-                # ppermute), so replicated logits are BITWISE equal to
-                # the 1-replica placed path; the gspmd executor's 2-D
-                # partition can re-layout ops (~1e-10 drift)
-                return pp.pipeline_apply_hetero(
-                    stage_fns, wires, mesh=mesh, stage_axis="stage",
-                    n_stages=s, stage_params=pb, n_replicas=r)
-            return pp.pipeline_apply_gspmd_hetero(
-                stage_fns, wires, n_stages=s, stage_axis="stage",
-                mesh=mesh, stage_params=pb)
+            # shard_map: every device runs literally its own stage's
+            # program (lax.switch + ppermute). GSPMD cannot partition a
+            # Mosaic kernel, so the gspmd executor cannot hold the
+            # Pallas path on a placed mesh; and replicated logits stay
+            # BITWISE equal to the 1-replica placed path
+            return pp.pipeline_apply_hetero(
+                stage_fns, wires, mesh=mesh, stage_axis="stage",
+                n_stages=s, stage_params=pb, n_replicas=r)
     else:
         stage_fns, pack_in, unpack_out, width = cnn.stage_programs(
             cfg, params, plan["stage_of"], mb_shape, quantize=quantize)
@@ -404,16 +401,16 @@ def _serve_cnn(arch: str, *, batch: int = 16, n_microbatches: int = 4,
 
     pack = jax.vmap(jax.vmap(pack_in)) if r > 1 else jax.vmap(pack_in)
 
-    @jax.jit
-    def run(xmb, *pb):
+    def run_fn(xmb, *pb):
         out = pipeline(pack(xmb), *pb)
         return pp.concat_hetero_outputs(out, unpack_out, n_microbatches,
                                         n_replicas=r)
 
     with _mesh_ctx(mesh):
         t0 = time.time()
-        logits = jax.block_until_ready(run(*run_args))
+        run = jax.jit(run_fn).lower(*run_args).compile()
         compile_s = time.time() - t0
+        logits = jax.block_until_ready(run(*run_args))     # warmup
         t0 = time.time()
         for _ in range(iters):
             logits = jax.block_until_ready(run(*run_args))
@@ -438,11 +435,15 @@ def _serve_cnn(arch: str, *, batch: int = 16, n_microbatches: int = 4,
                   f"replicated (placement would hold "
                   f"{placed_bytes / 1e6:.2f} MB, x{x:.1f} smaller)")
     return {"logits": np.asarray(logits), "images_per_s": ims_per_s,
+            "request_images": np.asarray(images),
             "compile_s": compile_s, "run_s": run_s,
+            "mosaic_kernels": _mosaic_kernels(run),
             "bubble_fraction": bub, "n_stages": s,
             "n_replicas": r,
             "imbalance": plan["imbalance"],
             "placed": use_placed,
+            "stage_row_devices": (_row_devices(run_args[1])
+                                  if use_placed else None),
             "quantize": quantize,
             "param_bytes_replicated_per_device": int(total_bytes),
             "param_bytes_placed_per_device": int(placed_bytes),
@@ -477,18 +478,19 @@ def _serve_cnn_latency(cfg: ServeConfig) -> dict:
     stage_fns, pack_in, unpack_out, width = cnn.stage_programs(
         mcfg, params, plan["stage_of"], img_shape, quantize=cfg.quantize)
 
-    @jax.jit
-    def request(img):
+    def request_fn(img):
         wire = pack_in(img)
         for fn in stage_fns:          # composed, not pipelined: one jit
             wire = fn(wire)
         return unpack_out(wire)
 
-    # one warmup request eats the compile; the timed loop measures the
-    # steady single-image round trip
+    # compile ahead of time, then one warmup request; the timed loop
+    # measures the steady single-image round trip
     t0 = time.time()
-    jax.block_until_ready(request(jnp.zeros(img_shape, jnp.float32)))
+    request = jax.jit(request_fn).lower(
+        jax.ShapeDtypeStruct(img_shape, jnp.float32)).compile()
     compile_s = time.time() - t0
+    jax.block_until_ready(request(jnp.zeros(img_shape, jnp.float32)))
     key = jax.random.PRNGKey(cfg.seed + 1)
     reqs = np.asarray(jax.random.normal(
         key, (cfg.n_requests,) + img_shape[1:]), np.float32)
@@ -512,7 +514,33 @@ def _serve_cnn_latency(cfg: ServeConfig) -> dict:
             "request_latencies_s": lats, "logits": logits,
             "request_images": reqs,
             "n_stages": int(plan["n_stages"]), "compile_s": compile_s,
+            "mosaic_kernels": _mosaic_kernels(request),
             "param_bytes_stored": int(total_bytes)}
+
+
+def _mosaic_kernels(compiled) -> dict:
+    """Mosaic-compiled Pallas kernel calls in a compiled program, by
+    kernel name (``{"sparse_conv": 53, ...}``; empty off TPU, where
+    Pallas kernels are interpreted into plain HLO)."""
+    counts = {}
+    for line in compiled.as_text().splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            name = line.split("=", 1)[0].strip().lstrip("%")
+            name = name.rsplit(".", 1)[0] if "." in name else name
+            counts[name] = counts.get(name, 0) + 1
+    return counts
+
+
+def _row_devices(buf) -> list:
+    """Per stage row of a placed ``(S, P)`` param buffer, the ids of
+    the devices that hold it — the physical evidence of placement."""
+    rows = [[] for _ in range(buf.shape[0])]
+    for sh in buf.addressable_shards:
+        start = sh.index[0].start or 0
+        stop = sh.index[0].stop or buf.shape[0]
+        for k in range(start, stop):
+            rows[k].append(sh.device.id)
+    return [sorted(r) for r in rows]
 
 
 # marks a microbatch slot owned by the serving tier rather than a
@@ -1226,6 +1254,8 @@ def main(argv=None):
                          "packs per-channel-scaled codes into the "
                          "placed param rows")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.dial:
         # worker side of the cross-host tier: delegate to the worker
         # entry point with the model args this CLI already knows.

@@ -484,13 +484,16 @@ class NetFaultProxy:
         if not chunks:
             self.frames_dropped[direction] += 1
             return
+        # counted before the bytes leave: a peer that has read the frame
+        # must already see it counted
+        self.frames_forwarded[direction] += 1
         try:
             for c in chunks:
                 if c:
                     dst.sendall(c)
         except OSError as e:
+            self.frames_forwarded[direction] -= 1
             raise _DropConn from e
-        self.frames_forwarded[direction] += 1
 
 
 # --- elastic re-meshing ------------------------------------------------------

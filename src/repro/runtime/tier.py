@@ -97,6 +97,30 @@ class NoHealthyReplicaError(TierError):
     per-request."""
 
 
+class DeviceHeldError(TierError):
+    """The supervisor holds an accelerator its worker processes would
+    need: a chip belongs to one process at a time."""
+
+
+def check_workers_can_reach_device(what: str) -> None:
+    """Raise :class:`DeviceHeldError` unless JAX runs on the CPU here.
+
+    The process tiers compile and plan in the supervisor (so it has
+    initialized JAX) and then start worker interpreters that each
+    initialize JAX again. On an accelerator host the supervisor already
+    holds the chip, so every worker would fail or hang at start-up;
+    refuse before spawning any."""
+    import jax
+    platform = jax.default_backend()
+    if platform != "cpu":
+        raise DeviceHeldError(
+            f"{what} starts worker processes that each need the "
+            f"{platform!r} device, but this supervisor process has "
+            f"initialized JAX on {platform!r} and holds the chip; a "
+            "worker would fail or hang. Serve in one process "
+            "(serve(ServeConfig(...)) without procs/hosts) instead.")
+
+
 # --- generalized request + admission (refactored out of scheduler.py) -------
 
 @dataclass
@@ -899,6 +923,7 @@ class ProcessServingTier(_TierBase):
             suspect_after_s=suspect_after_s, dead_after_s=dead_after_s)
         if n_procs < 1:
             raise ValueError(f"n_procs must be >= 1, got {n_procs}")
+        check_workers_can_reach_device(type(self).__name__)
         import jax
         from repro.configs import get_config
         from repro.core import planner

@@ -46,6 +46,7 @@ from repro.configs import get_config
 from repro.core import pipeline as pp, planner
 from repro.core.costmodel import pytree_param_bytes
 from repro.core.fusion import fused_graph_for
+from repro.launch.mesh import make_mesh
 from repro.launch.shardings import stage_param_shardings
 from repro.models import cnn
 
@@ -57,6 +58,15 @@ def _cfg(arch: str, sparse: bool):
             cfg.sparsity, enabled=sparse,
             block_m=min(cfg.sparsity.block_m, 32),
             block_n=min(cfg.sparsity.block_n, 32)))
+
+
+def _sequential(cfg, params, x_mb):
+    """The sequential interpreter over the same microbatches the
+    pipeline runs. Per microbatch, not on the whole batch: XLA:CPU's
+    dense conv may sum in an order that depends on the batch size (the
+    oneDNN bf16 convs of AMX hosts do), which no pipeline controls."""
+    fwd = jax.jit(lambda p, x: cnn.cnn_forward(cfg, p, x))
+    return jnp.concatenate([fwd(params, x) for x in x_mb], 0)
 
 
 def check(arch: str, sparse: bool, *, n_stages=4, img=32, batch=4, m=2):
@@ -72,14 +82,14 @@ def check(arch: str, sparse: bool, *, n_stages=4, img=32, batch=4, m=2):
     stage_fns, pack_in, unpack_out, _ = cnn.stage_programs(
         cfg, params, plan["stage_of"], x_mb.shape[1:])
     x_wire = jax.vmap(pack_in)(x_mb)
-    mesh = jax.make_mesh((s,), ("stage",))
-    mesh_ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
+    mesh = make_mesh((s,), ("stage",))
+    mesh_ctx = jax.set_mesh(mesh)
     with mesh_ctx:
         out_w = jax.jit(lambda xw: pp.pipeline_apply_hetero(
             stage_fns, xw, mesh=mesh, stage_axis="stage",
             n_stages=s))(x_wire)
     logits = jnp.concatenate([unpack_out(out_w[i]) for i in range(m)], 0)
-    ref = jax.jit(lambda p, x: cnn.cnn_forward(cfg, p, x))(params, imgs)
+    ref = _sequential(cfg, params, x_mb)
     assert logits.shape == ref.shape, (logits.shape, ref.shape)
     diff = float(jnp.abs(logits - ref).max())
     exact = bool(jnp.all(logits == ref))
@@ -120,7 +130,7 @@ def check_placed(arch: str, sparse: bool, *, n_stages=8, img=32, batch=4,
         assert pparams.width <= budget, (pparams.width, budget)
 
     # -- physical placement: device k's shard IS stage k's packed row --
-    mesh = jax.make_mesh((s,), ("stage",))
+    mesh = make_mesh((s,), ("stage",))
     sps = stage_param_shardings(g, plan, mesh, params=params)
     assert sps["placed_bytes_per_device"] == max(pparams.stage_bytes)
     assert sps["replicated_bytes_per_device"] == total
@@ -136,8 +146,8 @@ def check_placed(arch: str, sparse: bool, *, n_stages=8, img=32, batch=4,
 
     # -- placed pipelined == sequential interpreter, BITWISE --
     x_wire = jax.vmap(pack_in)(x_mb)
-    ref = jax.jit(lambda p, x: cnn.cnn_forward(cfg, p, x))(params, imgs)
-    mesh_ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
+    ref = _sequential(cfg, params, x_mb)
+    mesh_ctx = jax.set_mesh(mesh)
     tag = "sparse" if sparse else "dense"
     with mesh_ctx:
         out_w = jax.jit(lambda xw, pb: pp.pipeline_apply_hetero(
@@ -184,7 +194,7 @@ def check_stage_data(arch: str, sparse: bool, *, n_stages=4, n_replicas=2,
         cfg, params, plan, x1.shape[1:])
     buf1 = jax.device_put(pp1.pack(), sps1["buffer"])
     xw1 = jax.vmap(pin1)(x1)
-    ctx1 = jax.set_mesh(mesh1) if hasattr(jax, "set_mesh") else mesh1
+    ctx1 = jax.set_mesh(mesh1)
     with ctx1:
         o1 = jax.jit(lambda xw, pb: pp.pipeline_apply_hetero(
             fns1, xw, mesh=mesh1, stage_axis="stage", n_stages=s,
@@ -213,7 +223,7 @@ def check_stage_data(arch: str, sparse: bool, *, n_stages=4, n_replicas=2,
         np.testing.assert_array_equal(row[0], host_rows[k])
 
     xw2 = jax.vmap(jax.vmap(pin2))(x2)
-    ctx2 = jax.set_mesh(mesh2) if hasattr(jax, "set_mesh") else mesh2
+    ctx2 = jax.set_mesh(mesh2)
     with ctx2:
         o2 = jax.jit(lambda xw, pb: pp.pipeline_apply_hetero(
             fns2, xw, mesh=mesh2, stage_axis="stage", n_stages=s,

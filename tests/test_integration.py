@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from repro.configs import all_configs, get_config, reduced
+from repro.launch.mesh import make_mesh
 
 
 def test_train_loss_decreases():
@@ -60,7 +61,7 @@ def test_param_shardings_consistent_all_archs():
     """Every param/cache leaf gets a spec whose sharded dims divide."""
     from repro.launch import shardings as sh
     from repro.models import lm
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
 
     class FakeMesh:                      # 16x16 shape lookup only
         axis_names = ("data", "model")

@@ -31,7 +31,8 @@ def test_sparse_matmul_allclose(d_in, d_out, bm, bn, sp, dtype):
     np.testing.assert_allclose(np.asarray(y_xla, np.float32),
                                np.asarray(y_ref, np.float32),
                                rtol=tol, atol=tol)
-    y_pal = sparse_matmul_pallas(x, sw.vals, sw.idx, block_m_x=8)
+    y_pal = sparse_matmul_pallas(x, sw.vals, sw.idx, block_m_x=8,
+                                 interpret=True)
     np.testing.assert_allclose(np.asarray(y_pal, np.float32),
                                np.asarray(y_ref, np.float32),
                                rtol=tol, atol=tol)
@@ -66,7 +67,8 @@ def test_flash_attention_allclose(tq, tk, causal, window, dtype):
     want = ref.attention_ref(q, k, v, causal=causal, window=window,
                              q_offset=offset)
     got = flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                 q_offset=offset, block_q=32, block_k=64)
+                                 q_offset=offset, block_q=32, block_k=64,
+                                 interpret=True)
     tol = 2e-5 if dtype == jnp.float32 else 4e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
@@ -101,7 +103,8 @@ def test_depthwise_conv_allclose(k, stride, c, hw, dtype):
     x = jax.random.normal(k1, (2, hw, hw, c), jnp.float32).astype(dtype)
     w = jax.random.normal(k2, (k, k, c), jnp.float32).astype(dtype)
     want = depthwise_conv_ref(x, w, stride=stride)
-    got = depthwise_conv_pallas(x, w, stride=stride, block_c=min(c, 8))
+    got = depthwise_conv_pallas(x, w, stride=stride, block_c=min(c, 8),
+                                interpret=True)
     tol = 1e-5 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
@@ -128,8 +131,7 @@ def test_depthwise_conv_largest_mobilenet_shape_fits_vmem():
                 tc = pick_block_c(s.in_hw, s.cin, s.k, s.stride, itemsize)
                 assert s.cin % tc == 0
                 wo = -(-s.in_hw // s.stride)
-                wp = s.in_hw + max((wo - 1) * s.stride + s.k - s.in_hw,
-                                   0) + s.stride - 1
+                wp = (wo - 1) * s.stride + s.k
                 assert _vmem_bytes(wp, wo, tc, s.k, itemsize) \
                     <= VMEM_BUDGET_BYTES, (arch, s.name, tc)
     # the worst offender end-to-end: 112x112, C=128 (old kernel: ~13 MB
@@ -138,7 +140,8 @@ def test_depthwise_conv_largest_mobilenet_shape_fits_vmem():
     x = jax.random.normal(k1, (1, 112, 112, 128), jnp.float32)
     w = jax.random.normal(k2, (3, 3, 128), jnp.float32)
     want = depthwise_conv_ref(x, w, stride=1)
-    got = depthwise_conv_pallas(x, w, stride=1)      # auto block_c
+    got = depthwise_conv_pallas(x, w, stride=1,      # auto block_c
+                                interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
 
@@ -160,3 +163,61 @@ def test_mobilenet_forward_with_pallas_depthwise():
         pal_logits = cnn.cnn_forward(cfg, params, img)
     np.testing.assert_allclose(np.asarray(pal_logits), np.asarray(ref_logits),
                                rtol=2e-2, atol=2e-2)
+
+
+# -- kernel path chosen by platform -----------------------------------------
+
+@pytest.mark.parametrize("platform,impl,interpret", [
+    ("tpu", "pallas", False), ("cpu", "xla", True)])
+def test_kernel_path_follows_platform(monkeypatch, platform, impl,
+                                      interpret):
+    """Mosaic-compiled Pallas on TPU, the XLA twins on CPU (where a
+    Pallas kernel only ever runs interpreted). The platform is steered
+    inside the test."""
+    monkeypatch.setattr(ops, "_IMPL", None)
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    assert ops.impl() == impl
+    assert ops.interpret_mode() is interpret
+    with ops.set_impl("xla"):               # an explicit choice wins
+        assert ops.impl() == "xla"
+
+
+def test_unknown_platform_or_impl_raises(monkeypatch):
+    monkeypatch.setattr(ops, "_IMPL", None)
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="no kernel path"):
+        ops.impl()
+    with pytest.raises(RuntimeError, match="no kernel path"):
+        ops.interpret_mode()
+    with pytest.raises(ValueError, match="kernel impl"):
+        ops.set_impl("mosaic")
+    with pytest.raises(ValueError, match="kernel impl"):
+        with ops.config(impl="mosaic"):
+            pass
+    assert ops._IMPL is None                # nothing half-applied
+
+
+def test_unknown_impl_env_raises_at_import():
+    """REPRO_KERNEL_IMPL is checked, never silently replaced by xla."""
+    import os
+    import subprocess
+    import sys
+    env = dict(os.environ, REPRO_KERNEL_IMPL="mosaic")
+    r = subprocess.run([sys.executable, "-c", "import repro.kernels.ops"],
+                       env=env, capture_output=True, text=True,
+                       timeout=300)
+    assert r.returncode != 0
+    assert "kernel impl 'mosaic'" in r.stderr
+
+
+def test_no_kernel_defaults_to_interpret():
+    """Every Pallas kernel takes ``interpret`` explicitly: a default of
+    True would run the interpreter on a TPU."""
+    import inspect
+    from repro.kernels.depthwise_conv import depthwise_conv_pallas
+    from repro.kernels.dw_pw_fused import dw_pw_pallas
+    from repro.kernels.sparse_conv import sparse_conv_pallas
+    for fn in (sparse_matmul_pallas, flash_attention_pallas,
+               depthwise_conv_pallas, dw_pw_pallas, sparse_conv_pallas):
+        p = inspect.signature(fn).parameters["interpret"]
+        assert p.default is inspect.Parameter.empty, fn

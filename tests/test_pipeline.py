@@ -38,7 +38,8 @@ _SUB = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P, NamedSharding
     from repro.core import pipeline as pp
-    mesh = jax.make_mesh((4, 2), ("pod", "data"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 2), ("pod", "data"))
     L, D = 8, 16
     key = jax.random.PRNGKey(0)
     blocks = {"w": jax.random.normal(key, (L, D, D)) * 0.1}
@@ -53,8 +54,7 @@ _SUB = textwrap.dedent("""
     ref = x
     for l in range(L):
         ref = block_fn({"w": blocks["w"][l]}, ref)
-    # jax.set_mesh is 0.5+; on 0.4.x the Mesh itself is the context
-    mesh_ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
+    mesh_ctx = jax.set_mesh(mesh)
     with mesh_ctx:
         out1 = jax.jit(lambda sp, m, xmb: pp.pipeline_apply(
             stage_fn, sp, m, xmb, mesh=mesh, stage_axis="pod",

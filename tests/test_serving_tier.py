@@ -259,3 +259,33 @@ def test_placed_tier_kill_replica_bitwise(placed_ref_tier):
     assert m["respawns"] == 1 and m["failed"] == 0
     for a, b in zip(ref, got):
         np.testing.assert_array_equal(a, b)
+
+
+# -- one process per chip: process tiers refuse on an accelerator host --------
+
+@pytest.mark.parametrize("tier_cls", ["ProcessServingTier",
+                                      "HostServingTier"])
+def test_process_tier_refuses_when_supervisor_holds_device(monkeypatch,
+                                                            tier_cls):
+    """On a TPU host the supervisor has initialized JAX and holds the
+    chip, so worker processes could never reach it: the tier raises at
+    once, naming the cause, and spawns nothing. The platform is steered
+    inside the test; the real backend here is the CPU."""
+    import subprocess
+    spawned = []
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(subprocess, "Popen",
+                        lambda *a, **k: spawned.append(a))
+    with pytest.raises(T.DeviceHeldError, match="holds the chip"):
+        getattr(T, tier_cls)(ARCH, n_procs=1, n_stages=2, image_size=IMG)
+    assert not spawned
+
+
+def test_serve_procs_refuses_when_supervisor_holds_device(monkeypatch):
+    """The same guard on the front door: serve(ServeConfig(procs=...))
+    on an accelerator host fails before any worker starts."""
+    from repro.launch.serve import ServeConfig, serve
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(T.DeviceHeldError):
+        serve(ServeConfig(arch=ARCH, procs=2, n_stages=2, image_size=IMG,
+                          verbose=False))

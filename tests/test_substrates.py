@@ -15,6 +15,7 @@ from repro.checkpoint import ckpt
 from repro.configs.base import SparsityConfig
 from repro.core import sparsity as S
 from repro.data.pipeline import DataConfig, MarkovStream, image_batch
+from repro.launch.mesh import make_mesh
 from repro.optim import adamw
 from repro.runtime import fault
 
@@ -184,7 +185,7 @@ def test_compression_error_feedback_unbiased():
 
 
 def test_remesh_changes_device_layout():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     tree = {"w": jnp.ones((8, 4))}
     from jax.sharding import PartitionSpec as P
     out = fault.remesh(tree, mesh, mesh, lambda p, l: P())

@@ -229,9 +229,11 @@ def test_sparse_conv_block_k_bitwise():
         enabled=True, sparsity=0.5, block_m=bm, block_n=bn))
     n_k = sw.vals.shape[1]
     from repro.kernels.sparse_conv import sparse_conv_pallas
-    base = sparse_conv_pallas(x, sw.vals, sw.idx, b, k=k, block_k=1)
+    base = sparse_conv_pallas(x, sw.vals, sw.idx, b, k=k, block_k=1,
+                              interpret=True)
     for bk in [t for t in (2, 3, 4) if n_k % t == 0]:
-        got = sparse_conv_pallas(x, sw.vals, sw.idx, b, k=k, block_k=bk)
+        got = sparse_conv_pallas(x, sw.vals, sw.idx, b, k=k, block_k=bk,
+                                 interpret=True)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(base))
 
 
@@ -252,9 +254,9 @@ def test_depthwise_block_c_bitwise():
     from repro.kernels.depthwise_conv import depthwise_conv_pallas
     x = jax.random.normal(KEY, (1, 16, 16, 16), jnp.float32)
     w = jax.random.normal(KEY, (3, 3, 16), jnp.float32)
-    base = depthwise_conv_pallas(x, w, block_c=16)
+    base = depthwise_conv_pallas(x, w, block_c=16, interpret=True)
     for tc in (4, 8):
-        got = depthwise_conv_pallas(x, w, block_c=tc)
+        got = depthwise_conv_pallas(x, w, block_c=tc, interpret=True)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(base))
 
 
