@@ -533,16 +533,19 @@ def _run_hetero_stages(stage_fns, state, stage_params, *, replicated):
     (S, mb, W), or (S, R, mb, W) with ``replicated`` — each replica
     slot gets its OWN trace of the stage program (no vmap), so the
     per-sample computation graph is identical to the 1-replica path
-    and replicated output is bitwise-equal to single-replica output."""
+    and replicated output is bitwise-equal to single-replica output.
+    Each stage's ops carry the named scope ``stage<k>``, so a profile
+    can tell the stages apart (metadata only: the program is unchanged)."""
     placed = stage_params is not None
 
     def one(k, st_k):
         fn = stage_fns[k]
         args = (stage_params[k],) if placed else ()
-        if replicated:
-            return jnp.stack([fn(*args, st_k[r])
-                              for r in range(st_k.shape[0])])
-        return fn(*args, st_k)
+        with jax.named_scope(f"stage{k}"):
+            if replicated:
+                return jnp.stack([fn(*args, st_k[r])
+                                  for r in range(st_k.shape[0])])
+            return fn(*args, st_k)
 
     return jnp.stack([one(k, state[k]) for k in range(len(stage_fns))])
 

@@ -57,6 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, reduced
+from repro.launch.compile_cache import backend_compiles
 from repro.launch.mesh import mesh_context as _mesh_ctx
 from repro.models import lm
 
@@ -531,6 +532,13 @@ def _mosaic_kernels(compiled) -> dict:
     return counts
 
 
+def _span(name: str, **args):
+    """A host span ``name`` in the profiler's trace, on the same clock
+    as the device ops; ``args`` (a request id, a tick number) go in as
+    TraceMe arguments. While no trace is recorded it costs one check."""
+    return jax.profiler.TraceAnnotation(name, **args)
+
+
 def _row_devices(buf) -> list:
     """Per stage row of a placed ``(S, P)`` param buffer, the ids of
     the devices that hold it — the physical evidence of placement."""
@@ -696,43 +704,38 @@ class CNNPipelineServer:
         self._req_submit = {}
         self._req_done = {}
 
-    @property
-    def idle_slots(self) -> int:
-        """Pipeline slots that ran empty over the server's lifetime
-        (fill/flush ticks + unfilled replica slots) — derived from the
-        tick counters, so it always agrees with the reported bubble."""
-        return self.ticks * self.n_replicas - self.injected_slots
-
     # -- request intake ----------------------------------------------------
 
     def submit(self, images) -> int:
         """Queue one request (B, H, W, 3). Returns a request id whose
         logits ``results()`` yields after ``run()``."""
-        images = np.asarray(images, np.float32)
-        b = images.shape[0]
-        if b == 0:
-            raise ValueError("empty request (batch 0)")
-        if images.shape[1:] != (self.image_size, self.image_size, 3):
-            raise ValueError(f"request shape {images.shape[1:]} != "
-                             f"({self.image_size}, {self.image_size}, 3)")
-        req = self._next_req
-        self._next_req += 1
-        n_mb = -(-b // self.mb_size)
-        self._pending[req] = n_mb
-        self._results[req] = [None] * n_mb
-        # monotonic, not wall time: request latencies are durations,
-        # and an NTP step must never produce negative (or day-long)
-        # p50s — wall clocks are for logs only
-        self._req_submit[req] = time.monotonic()
-        for i in range(n_mb):
-            chunk = images[i * self.mb_size:(i + 1) * self.mb_size]
-            n_valid = chunk.shape[0]
-            if n_valid < self.mb_size:
-                chunk = np.concatenate(
-                    [chunk, np.zeros((self.mb_size - n_valid,)
-                                     + chunk.shape[1:], np.float32)])
-            self._queue.append((req, i, n_valid, chunk))
-        return req
+        with _span("serve.submit", req=self._next_req):
+            images = np.asarray(images, np.float32)
+            b = images.shape[0]
+            if b == 0:
+                raise ValueError("empty request (batch 0)")
+            if images.shape[1:] != (self.image_size, self.image_size, 3):
+                raise ValueError(
+                    f"request shape {images.shape[1:]} != "
+                    f"({self.image_size}, {self.image_size}, 3)")
+            req = self._next_req
+            self._next_req += 1
+            n_mb = -(-b // self.mb_size)
+            self._pending[req] = n_mb
+            self._results[req] = [None] * n_mb
+            # monotonic, not wall time: request latencies are durations,
+            # and an NTP step must never produce negative (or day-long)
+            # p50s — wall clocks are for logs only
+            self._req_submit[req] = time.monotonic()
+            for i in range(n_mb):
+                chunk = images[i * self.mb_size:(i + 1) * self.mb_size]
+                n_valid = chunk.shape[0]
+                if n_valid < self.mb_size:
+                    chunk = np.concatenate(
+                        [chunk, np.zeros((self.mb_size - n_valid,)
+                                         + chunk.shape[1:], np.float32)])
+                self._queue.append((req, i, n_valid, chunk))
+            return req
 
     def enqueue(self, key, images, *, n_valid=None):
         """Tier hook: queue ONE microbatch whose logits are delivered
@@ -774,16 +777,17 @@ class CNNPipelineServer:
         r = self.n_replicas
         slots = [self._queue.popleft() if self._queue else None
                  for _ in range(r)] if r > 1 else [self._queue.popleft()]
-        imgs = np.stack([s[3] if s is not None else
-                         np.zeros((self.mb_size, self.image_size,
-                                   self.image_size, 3), np.float32)
-                         for s in slots])
-        wire = self._pack(jnp.asarray(imgs) if r > 1
-                          else jnp.asarray(imgs[0]))
-        if self.mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            spec = P("data") if r > 1 else P()
-            wire = jax.device_put(wire, NamedSharding(self.mesh, spec))
+        with _span("serve.stage_next"):
+            imgs = np.stack([s[3] if s is not None else
+                             np.zeros((self.mb_size, self.image_size,
+                                       self.image_size, 3), np.float32)
+                             for s in slots])
+            wire = self._pack(jnp.asarray(imgs) if r > 1
+                              else jnp.asarray(imgs[0]))
+            if self.mesh is not None:
+                from jax.sharding import NamedSharding, PartitionSpec as P
+                spec = P("data") if r > 1 else P()
+                wire = jax.device_put(wire, NamedSharding(self.mesh, spec))
         return slots, wire
 
     def _collect(self, slots, out_wire):
@@ -797,8 +801,9 @@ class CNNPipelineServer:
             if slot is None:
                 continue
             req, i, n_valid, _ = slot
-            logits = np.asarray(self._unpack_out(
-                out_wire[k] if r > 1 else out_wire))[:n_valid]
+            with _span("serve.collect"):
+                logits = np.asarray(self._unpack_out(
+                    out_wire[k] if r > 1 else out_wire))[:n_valid]
             if req is _EXTERNAL:
                 self.on_result(i, logits)      # i is the tier's key
                 continue
@@ -827,36 +832,41 @@ class CNNPipelineServer:
                 self._collect(*self._emitted)
                 self._emitted = None
             return False
-        slots, wire = self._staged if self._staged is not None \
-            else (None, self._zero_wire)
-        self._state, out = self._step(self._state, wire,
-                                      *self._params_arg)
-        self.ticks += 1
-        if slots is not None:
-            self.injected_slots += sum(1 for s in slots
-                                       if s is not None)
-        self._inflight.append(slots)
-        self._staged = self._stage_next()     # H2D overlaps the step
-        # collect the PREVIOUS tick's output only now, after this tick
-        # is dispatched: its D2H readback overlaps the in-flight
-        # compute instead of serializing it
-        if self._emitted is not None:
-            self._collect(*self._emitted)
-            self._emitted = None
-        if len(self._inflight) >= self.n_stages:
-            self._emitted = (self._inflight.popleft(), out)
+        with _span("serve.tick", tick=self.ticks):
+            slots, wire = self._staged if self._staged is not None \
+                else (None, self._zero_wire)
+            with _span("serve.dispatch"):
+                self._state, out = self._step(self._state, wire,
+                                              *self._params_arg)
+            self.ticks += 1
+            if slots is not None:
+                self.injected_slots += sum(1 for s in slots
+                                           if s is not None)
+            self._inflight.append(slots)
+            self._staged = self._stage_next()     # H2D overlaps the step
+            # collect the PREVIOUS tick's output only now, after this
+            # tick is dispatched: its D2H readback overlaps the
+            # in-flight compute instead of serializing it
+            if self._emitted is not None:
+                self._collect(*self._emitted)
+                self._emitted = None
+            if len(self._inflight) >= self.n_stages:
+                self._emitted = (self._inflight.popleft(), out)
         return True
 
     def run(self) -> dict:
         """Drain the queue: one pipeline tick per queued microbatch
         (continuous injection — no drain between requests) plus S-1
-        flush ticks. Returns throughput/bubble metrics for the run."""
+        flush ticks. Returns throughput/bubble metrics for the run, and
+        ``compiles``: backend compiles in this process while it ran (0
+        once every shape is warm)."""
         t0 = time.monotonic()
         n_imgs = sum(s[2] for s in self._queue)
         ticks_before = self.ticks
         injected_before = self.injected_slots
+        compiles_before = backend_compiles()
         done_before = set(self._req_done)
-        with _mesh_ctx(self.mesh):
+        with _span("serve.run"), _mesh_ctx(self.mesh):
             if self._staged is None:
                 self._staged = self._stage_next()
             while self._staged is not None or any(
@@ -891,6 +901,7 @@ class CNNPipelineServer:
             "fill_bubble_single_batch": None,
             "n_stages": self.n_stages,
             "n_replicas": self.n_replicas,
+            "compiles": backend_compiles() - compiles_before,
         }
         if self.verbose:
             print(f"{self.cfg.name}: served {n_imgs} imgs in {ticks} "
@@ -903,16 +914,17 @@ class CNNPipelineServer:
         entry is evicted on delivery, so a long-running server's
         memory stays bounded by in-flight requests, not its history
         (a second call raises the unknown-request error)."""
-        if req not in self._pending:
-            raise KeyError(f"unknown request id {req}")
-        if self._pending[req] != 0:
-            raise ValueError(f"request {req} incomplete "
-                             f"({self._pending[req]} microbatches "
-                             "outstanding); call run() first")
-        del self._pending[req]
-        self._req_submit.pop(req, None)
-        self._req_done.pop(req, None)
-        return np.concatenate(self._results.pop(req), axis=0)
+        with _span("serve.results", req=req):
+            if req not in self._pending:
+                raise KeyError(f"unknown request id {req}")
+            if self._pending[req] != 0:
+                raise ValueError(f"request {req} incomplete "
+                                 f"({self._pending[req]} microbatches "
+                                 "outstanding); call run() first")
+            del self._pending[req]
+            self._req_submit.pop(req, None)
+            self._req_done.pop(req, None)
+            return np.concatenate(self._results.pop(req), axis=0)
 
     # -- failure recovery (the tier's drain-and-respawn contract) ----------
 

@@ -330,7 +330,8 @@ def _interpret(g: LayerGraph, params, x, *, start=0, stop=None,
     arrays and must contain every value the slice reads; returns the
     env extended with each executed node's output. Dead values are NOT
     freed here — slicing callers (stage programs) bound liveness via
-    the wire contract instead."""
+    the wire contract instead. Each node's ops carry the named scope of
+    its name, so a profile tells fused nodes apart."""
     env = dict(env or {})
     if x is not None:
         env[INPUT] = x
@@ -338,7 +339,8 @@ def _interpret(g: LayerGraph, params, x, *, start=0, stop=None,
     for i in range(start, stop):
         node = g.nodes[i]
         args = [env[src] for src in g.inputs[i]]
-        env[node.name] = run_node(node, params, *args)
+        with jax.named_scope(node.name):
+            env[node.name] = run_node(node, params, *args)
     return env
 
 
@@ -453,19 +455,24 @@ def stage_programs(cfg, params, stage_of, image_shape, *,
         placed_params = pp.PlacedParams(formats=tuple(pfmts),
                                         trees=tuple(trees), width=pwidth)
 
+    # named scopes (metadata only) split a profile of a stage into its
+    # weight decode (``params``), wire codecs and nodes
     def make_stage(sl, in_fmt, out_fmt, pfmt=None):
-        def stage(wire):
-            env = dict(zip(sl.in_live, in_fmt.unpack(wire)))
-            env = _interpret(g, params, None, start=sl.start, stop=sl.stop,
-                             env=env)
-            return out_fmt.pack([env[n] for n in sl.out_live], width)
-
-        def stage_placed(pbuf, wire):
-            sparams = pfmt.unpack(pbuf)
-            env = dict(zip(sl.in_live, in_fmt.unpack(wire)))
+        def run(sparams, wire):
+            with jax.named_scope("wire_in"):
+                env = dict(zip(sl.in_live, in_fmt.unpack(wire)))
             env = _interpret(g, sparams, None, start=sl.start, stop=sl.stop,
                              env=env)
-            return out_fmt.pack([env[n] for n in sl.out_live], width)
+            with jax.named_scope("wire_out"):
+                return out_fmt.pack([env[n] for n in sl.out_live], width)
+
+        def stage(wire):
+            return run(params, wire)
+
+        def stage_placed(pbuf, wire):
+            with jax.named_scope("params"):
+                sparams = pfmt.unpack(pbuf)
+            return run(sparams, wire)
 
         return stage_placed if pfmt is not None else stage
 
@@ -478,10 +485,12 @@ def stage_programs(cfg, params, stage_of, image_shape, *,
                      for sl, fi, fo in zip(slices, in_fmts, out_fmts)]
 
     def pack_in(images):
-        return in_fmts[0].pack([images.astype(jnp.bfloat16)], width)
+        with jax.named_scope("pack_in"):
+            return in_fmts[0].pack([images.astype(jnp.bfloat16)], width)
 
     def unpack_out(wire):
-        return out_fmts[-1].unpack(wire)[0]
+        with jax.named_scope("unpack_out"):
+            return out_fmts[-1].unpack(wire)[0]
 
     if placed:
         return stage_fns, pack_in, unpack_out, width, placed_params

@@ -49,3 +49,23 @@ def test_serve_cli_writes_entries_where_the_env_says(tmp_path):
     assert r.returncode == 0, r.stderr[-2000:]
     assert cache.is_dir() and any(cache.iterdir())
     assert not (tmp_path / ".jax_cache").exists()
+
+
+def test_backend_compiles_counts_each_new_program():
+    """The process-wide counter counts a backend compile once per new
+    program, and nothing for a call that reuses one."""
+    import numpy as np
+    from jax.experimental.compilation_cache import compilation_cache
+    x = np.ones(7, np.float32)
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()         # no entry from an earlier run
+    try:
+        before = compile_cache.backend_compiles()
+        f = jax.jit(lambda x: x * 3.0 + 1.0)
+        f(x).block_until_ready()
+        f(x).block_until_ready()
+        assert compile_cache.backend_compiles() - before == 1
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        compilation_cache.reset_cache()

@@ -108,3 +108,56 @@ def test_results_before_run_raises():
         srv.submit(np.zeros((2, IMG + 1, IMG + 1, 3), np.float32))
     srv.run()
     assert srv.results(req).shape == (2, 1000)
+
+
+def test_run_compiles_nothing_when_warm():
+    """run() reports the backend compiles made while it ran: a second
+    identical run() compiles nothing."""
+    srv = CNNPipelineServer(ARCH, mb_size=2, n_stages=3, image_size=IMG)
+    for _ in range(2):
+        reqs = [srv.submit(_imgs(13, 3)), srv.submit(_imgs(14, 2))]
+        m = srv.run()
+        for r in reqs:
+            srv.results(r)
+        assert m["injected_microbatches"] == 3
+        assert m["ticks"] == 3 + m["n_stages"] - 1
+    assert m["compiles"] == 0
+
+
+def test_spans_nest_in_a_profile(tmp_path):
+    """Under the JAX profiler the server's own host spans appear, under
+    their bare names, and nest: serve.run holds the ticks, a tick holds
+    its dispatch, its staging of the next microbatch and its readback."""
+    import glob
+
+    from jax.profiler import ProfileData
+    srv = CNNPipelineServer(ARCH, mb_size=2, n_stages=3, image_size=IMG)
+    warm = srv.submit(_imgs(15, 4))
+    srv.run()
+    srv.results(warm)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        req = srv.submit(_imgs(16, 4))
+        ticks = srv.run()["ticks"]
+        srv.results(req)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = [(e.name, e.start_ns, e.end_ns)
+             for p in ProfileData.from_file(path).planes
+             if p.name == "/host:CPU" for line in p.lines
+             for e in line.events if e.name.startswith("serve.")]
+
+    def named(n):
+        return [s for s in spans if s[0] == n]
+
+    def within(inner, outer):
+        return [i for i in named(inner) if any(
+            o[1] <= i[1] and i[2] <= o[2] for o in named(outer))]
+
+    assert len(named("serve.submit")) == len(named("serve.results")) == 1
+    assert len(named("serve.run")) == 1
+    assert len(within("serve.tick", "serve.run")) == ticks
+    for inner in ("serve.dispatch", "serve.stage_next", "serve.collect"):
+        assert within(inner, "serve.tick"), inner
+    assert len(within("serve.dispatch", "serve.tick")) == ticks
