@@ -337,17 +337,28 @@ class ParamFormat:
     even SparseWeight nodes per stage), but placing each stage's slice
     on only its own devices needs ONE static buffer type that a
     ``(n_stages, width)`` array sharded over the stage axis can carry.
-    Each leaf is bitcast to raw uint8 (``lax.bitcast_convert_type`` —
-    lossless for every dtype, unlike an f32 widening which would
-    corrupt int32 indices above 2^24), flattened and concatenated in
-    tree-flatten order, then padded to the common stage width. Unpack
-    is the exact inverse, so a stage program running on unpacked params
-    is BIT-IDENTICAL to one closing over the originals.
+    Leaves are laid out in tree-flatten order as raw uint8, then padded
+    to the common stage width. A leaf of itemsize ``k`` and ``n``
+    elements is written as ``k`` BYTE PLANES of ``n`` bytes, back to
+    back: plane ``j`` holds byte ``j`` (bits ``8j..8j+7``) of every
+    element. Itemsize-1 leaves (int8 codes, float8) are one plane.
+
+    Unpack runs inside every tick, so the layout is chosen for it: each
+    plane is a 1-D ``u8[n]`` slice, widened to the leaf's unsigned
+    integer width, shifted and OR-ed into place, then same-width
+    bitcast to the leaf dtype and reshaped. No intermediate has a
+    trailing dimension of the itemsize; on the TPU such a dimension
+    (``u8[..., 2]`` for bf16) is padded to 128 lanes, so decoding
+    through it rewrote ~64x the row's bytes every tick. Bit patterns
+    move through integer ops and bitcasts only (never a value cast,
+    which would corrupt int32 indices above 2^24 or canonicalize NaN
+    payloads), so a stage program running on unpacked params is
+    BIT-IDENTICAL to one closing over the originals.
 
     ``store_dtype`` (core/quant.py) re-stores float leaves narrow
     BEFORE layout: int8 codes and their per-channel f32 scales become
-    ordinary leaves of the (quantized) tree, so the same bitcast path
-    carries them and the roundtrip stays bit-exact on the stored bits.
+    ordinary leaves of the (quantized) tree, so the same byte planes
+    carry them and the roundtrip stays bit-exact on the stored bits.
     Quantization is idempotent, so ``pack`` normalizes its input
     unconditionally — callers may hand it either the original or the
     already-quantized tree.
@@ -375,18 +386,20 @@ class ParamFormat:
             meta.append((tuple(l.shape), dt))
         return cls(treedef, meta, store_dtype)
 
-    def _leaf_bytes(self):
-        return [int(np.prod(s, dtype=np.int64)) * d.itemsize
+    def _leaf_sizes(self):
+        """Per leaf: (element count, itemsize)."""
+        return [(int(np.prod(s, dtype=np.int64)), d.itemsize)
                 for s, d in self.leaves_meta]
 
     @property
     def nbytes(self) -> int:
         """Live bytes of this stage's params — the sum of its part
         leaves, NOT the padded buffer width."""
-        return sum(self._leaf_bytes())
+        return sum(n * k for n, k in self._leaf_sizes())
 
     def pack(self, tree, width: int) -> jax.Array:
-        """Param pytree -> (width,) uint8 buffer (zero-padded)."""
+        """Param pytree -> (width,) uint8 buffer of byte planes
+        (zero-padded). Runs once, on the host."""
         if self.store_dtype != "native":
             from repro.core.quant import quantize_tree
             tree = quantize_tree(tree, self.store_dtype)
@@ -396,28 +409,34 @@ class ParamFormat:
                              f"got {len(leaves)}")
         if self.nbytes > width:
             raise ValueError(f"param width {width} < payload {self.nbytes}")
-        segs = []
+        buf = np.zeros((width,), np.uint8)
+        off = 0
         for l, (shape, dt) in zip(leaves, self.leaves_meta):
             if tuple(l.shape) != shape or jnp.dtype(l.dtype) != dt:
                 raise ValueError(f"leaf mismatch: {l.shape}/{l.dtype} vs "
                                  f"{shape}/{dt}")
-            # bitcast, never astype: itemsize-1 dtypes (int8/float8) are
-            # a same-size bitcast — an astype would VALUE-convert and
-            # break the bit-exact round-trip
-            segs.append(lax.bitcast_convert_type(l, jnp.uint8).reshape(-1))
-        buf = (jnp.concatenate(segs) if segs
-               else jnp.zeros((0,), jnp.uint8))
-        return jnp.pad(buf, (0, width - buf.shape[0]))
+            # view, never astype: the planes carry the leaf's bits
+            word = np.asarray(l).reshape(-1).view(f"<u{dt.itemsize}")
+            for j in range(dt.itemsize):
+                buf[off:off + word.size] = (word >> (8 * j)).astype(np.uint8)
+                off += word.size
+        return jnp.asarray(buf)
 
     def unpack(self, buf: jax.Array):
         """(>= nbytes,) uint8 buffer -> the param pytree, bit-exact."""
         leaves, off = [], 0
-        for (shape, dt), size in zip(self.leaves_meta, self._leaf_bytes()):
-            seg = lax.slice_in_dim(buf, off, off + size, axis=0)
-            src = seg.reshape(shape + (dt.itemsize,)) if dt.itemsize > 1 \
-                else seg.reshape(shape)
-            leaves.append(lax.bitcast_convert_type(src, dt))
-            off += size
+        for (shape, dt), (n, k) in zip(self.leaves_meta, self._leaf_sizes()):
+            wide = jnp.dtype(f"uint{8 * k}")
+            word = lax.slice_in_dim(buf, off, off + n).astype(wide)
+            for j in range(1, k):
+                plane = lax.slice_in_dim(buf, off + j * n, off + (j + 1) * n)
+                # the shift count is a host constant: it lowers with no
+                # op_name, so where the tick's lowering merges equal
+                # counts across stages, no stage's scope loses an op
+                count = np.broadcast_to(wide.type(8 * j), (n,))
+                word = word | lax.shift_left(plane.astype(wide), count)
+            off += k * n
+            leaves.append(lax.bitcast_convert_type(word, dt).reshape(shape))
         return jax.tree_util.tree_unflatten(self.treedef, leaves)
 
 

@@ -302,6 +302,120 @@ def test_placed_params_ragged_accounting():
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+# -- byte-plane layout ------------------------------------------------------
+
+def _edge_bits(dt, n, seed):
+    """``n`` raw bit patterns of ``dt`` (as its unsigned word): zero,
+    one, the sign bit alone (-0.0, int min such as -2**31), sign and
+    one (a negative denormal), all ones (a NaN with payload, -1),
+    exponent all ones with low payload bits (NaN payloads), the largest
+    positive word, then random words."""
+    k = jnp.dtype(dt).itemsize
+    ut = np.dtype(f"<u{k}")
+    sign, ones = 1 << (8 * k - 1), (1 << (8 * k)) - 1
+    edge = [0, 1, sign, sign | 1, ones, ones ^ 1, (ones >> 1) ^ 2,
+            ones >> 1, sign >> 1, (sign >> 1) | 3]
+    rng = np.random.default_rng(seed)
+    rand = rng.integers(0, ones, size=max(n - len(edge), 0),
+                        dtype=np.uint64, endpoint=True)
+    return np.concatenate([np.array(edge, ut), rand.astype(ut)])[:n]
+
+
+PLANE_DTYPES = [jnp.bfloat16, jnp.float16, jnp.float32, jnp.int32,
+                jnp.uint32, jnp.int8, jnp.float8_e4m3fn]
+
+
+@pytest.mark.parametrize("dt", PLANE_DTYPES,
+                         ids=[jnp.dtype(d).name for d in PLANE_DTYPES])
+def test_param_format_byte_planes_roundtrip(dt):
+    """Every leaf dtype's bit patterns, edge cases included, survive
+    ``pack(width)``, ``pack_ragged()`` rows and ``PlacedParams.pack()``
+    rows, eagerly and under jit; a leaf of itemsize k and n elements is
+    k planes of n bytes, plane j holding byte j of each element; the
+    byte counts are the live bytes."""
+    dt = jnp.dtype(dt)
+    k, ut = dt.itemsize, np.dtype(f"<u{dt.itemsize}")
+    bits = {"m": _edge_bits(dt, 3 * 7, 0).reshape(3, 7),
+            "s": _edge_bits(dt, 3, 1)[2:].reshape(()),   # the sign bit
+            "e": np.zeros((0, 4), ut)}
+    trees = [{n: jnp.asarray(b.view(dt)) for n, b in bits.items()},
+             {"w": jnp.asarray(_edge_bits(dt, 40, 2).view(dt))}]
+    fmts = [pp.ParamFormat.for_tree(t) for t in trees]
+    assert [f.nbytes for f in fmts] == [(21 + 1) * k, 40 * k]
+    width = max(f.nbytes for f in fmts)
+    pparams = pp.PlacedParams(formats=tuple(fmts), trees=tuple(trees),
+                              width=width)
+    assert pparams.stage_widths == (22 * k, 40 * k)
+    assert pparams.padding_bytes == 2 * width - 62 * k
+
+    def same(out, tree):
+        for a, b in zip(jax.tree_util.tree_leaves(out),
+                        jax.tree_util.tree_leaves(tree)):
+            assert a.dtype == b.dtype and a.shape == b.shape
+            np.testing.assert_array_equal(np.asarray(a).view(ut),
+                                          np.asarray(b).view(ut))
+
+    # tree-flatten order is e, m, s: the empty leaf writes nothing
+    row = np.asarray(fmts[0].pack(trees[0], fmts[0].nbytes + 5))
+    m = bits["m"].reshape(-1)
+    for j in range(k):
+        np.testing.assert_array_equal(row[21 * j:21 * (j + 1)],
+                                      (m >> (8 * j)).astype(np.uint8))
+    assert not row[22 * k:].any()
+    same(fmts[0].unpack(jnp.asarray(row)), trees[0])
+    placed = pparams.pack()
+    for s, (f, t) in enumerate(zip(fmts, trees)):
+        for r in (placed[s], pparams.pack_ragged()[s]):
+            same(f.unpack(r), t)
+            same(jax.jit(f.unpack)(r), t)
+
+
+@pytest.fixture(scope="module")
+def mbv2_server():
+    """The benchmark's MobileNet-V2 server, cut to 32 px (its weights,
+    and so its stage rows, are those of 224 px)."""
+    from repro.launch.serve import CNNPipelineServer
+    return CNNPipelineServer("mobilenet_v2", mb_size=1, n_stages=4,
+                             image_size=32)
+
+
+def test_mobilenet_v2_stage_rows_keep_their_bytes(mbv2_server):
+    """The plane layout moves bytes, never adds any: MobileNet-V2's
+    stage rows hold their live bytes, and unpack bit-exactly."""
+    pparams = mbv2_server.pparams
+    widths = (412352, 964480, 1580800, 4018000)
+    assert pparams.stage_widths == widths
+    assert [f.nbytes for f in pparams.formats] == list(widths)
+    assert pparams.padding_bytes == 4 * 4018000 - sum(widths)
+    rows = mbv2_server._params_arg[0]
+    for f, t, r in zip(pparams.formats, pparams.trees, rows):
+        assert r.shape == (f.nbytes,)
+        for a, b in zip(jax.tree_util.tree_leaves(jax.jit(f.unpack)(r)),
+                        jax.tree_util.tree_leaves(t)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_weight_decode_has_no_itemsize_minor_dimension(mbv2_server):
+    """No op of a stage's weight decode in the tick makes an integer
+    array whose minor dimension is 2 or 4, the itemsize of a bf16 or
+    f32 leaf: the TPU pads such a dimension to 128 lanes, so a decode
+    through ``u8[..., 2]`` rewrites some 64x the row's bytes a tick."""
+    import re
+    s = mbv2_server
+    tick = s._step.lower(s._state, s._zero_wire, *s._params_arg).as_text(
+        dialect="hlo", debug_info=True)
+    decode, bad = 0, []
+    for line in tick.splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        out = re.search(r" = ([su](?:8|16|32|64))\[([\d,]*)\]", line)
+        if not (name and re.search(r"(^|/)stage\d+/params/", name.group(1))):
+            continue
+        decode += 1
+        if out and out.group(2).split(",")[-1] in ("2", "4"):
+            bad.append(line.strip()[:120])
+    assert decode and not bad, bad
+
+
 def test_ragged_stage_params_executor_contract():
     """Ragged rows run the single-host packed path; placement on a
     stage mesh still demands the even buffer (unequal widths cannot
