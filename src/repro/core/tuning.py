@@ -17,7 +17,7 @@ behavior. This module closes the loop:
    rates.
 3. **Retune** — :func:`autotune_graph` searches the small candidate
    lattices of the Pallas/XLA kernel knobs (depthwise ``block_c``,
-   sparse-conv ``block_k``, dw_pw ``row_chunk``) plus the serving
+   dw_pw ``row_chunk``) plus the serving
    microbatch width M, recording winners in the same cache; the kernel
    dispatchers (``kernels/ops.py``) consult the active cache at trace
    time.
@@ -47,8 +47,7 @@ __all__ = [
     "TuningCache", "device_signature", "node_key", "kernel_key",
     "graph_node_keys", "measure_graph", "seed_from_analytic",
     "measured_node_costs", "autotune_depthwise_block_c",
-    "autotune_dw_pw_row_chunk", "autotune_sparse_conv_block_k",
-    "autotune_microbatch", "autotune_graph", "calibrate",
+    "autotune_dw_pw_row_chunk", "autotune_microbatch", "autotune_graph", "calibrate",
     "set_tuning_cache", "current_tuning_cache",
 ]
 
@@ -225,7 +224,7 @@ def node_key(node, in_shape, dtype, wsig: str,
 
 def kernel_key(op: str, in_shape, dtype, *, device: Optional[str] = None,
                **fields) -> str:
-    """Cache key of one kernel-knob site (``op`` in dw | dwpw | sconv |
+    """Cache key of one kernel-knob site (``op`` in dw | dwpw |
     microbatch), same tail schema as node keys."""
     dev = device or device_signature()
     tail = "/".join(f"{k}{v}" for k, v in sorted(fields.items()))
@@ -442,33 +441,6 @@ def autotune_dw_pw_row_chunk(x, dw_w, dw_b, pw_w, pw_b, *, stride: int = 1,
     return int(best)
 
 
-def autotune_sparse_conv_block_k(x, sw, bias, *, k: int, stride: int = 1,
-                                 relu: bool = True, cache: TuningCache,
-                                 iters: int = 3) -> int:
-    """Search the sparse-conv Pallas kernel's K-tile (how many weight
-    blocks each grid step gathers+accumulates) over the divisors of the
-    node's kept-block count."""
-    from repro.kernels import ops as kops
-    from repro.kernels import sparse_conv as sc
-    import jax
-    n_k = sw.vals.shape[1]
-    cands = [t for t in (1, 2, 3, 4) if n_k % t == 0] or [1]
-    ob, _, bm, bn = sw.vals.shape
-    key = kernel_key("sconv", x.shape, x.dtype, k=k, s=stride,
-                     b=f"{bm}x{bn}K{n_k}", co=ob * bn)
-    best, best_us = 1, float("inf")
-    for t in cands:
-        fn = jax.jit(lambda a, _t=t: sc.sparse_conv_pallas(
-            a, sw.vals, sw.idx, bias, k=k, stride=stride, relu=relu,
-            block_k=_t, interpret=kops.interpret_mode()))
-        us = _time_call(fn, x, warmup=1, iters=iters)
-        if us < best_us:
-            best, best_us = t, us
-    cache.put_knob(key, "block_k", int(best))
-    cache.put_time(key, best_us)
-    return int(best)
-
-
 def autotune_microbatch(stage_cost, *, n_replicas: int = 1,
                         candidates=(2, 4, 8, 16, 32),
                         rel_tol: float = 0.05,
@@ -503,8 +475,8 @@ def autotune_graph(cfg, params, image_shape, *, graph=None,
                    cache: Optional[TuningCache] = None, iters: int = 3,
                    verbose: bool = False) -> TuningCache:
     """Walk the fused graph and tune every knob that applies to the
-    CURRENT kernel impl (Pallas: depthwise block_c + sparse-conv
-    block_k; XLA: dw_pw row_chunk). Winners land under kernel keys in
+    CURRENT kernel impl (Pallas: depthwise block_c; XLA: dw_pw
+    row_chunk). Winners land under kernel keys in
     the same cache the profiler uses; repeated shapes are tuned once."""
     import jax.numpy as jnp
     from repro.core.fusion import conv_part, fused_graph_for
@@ -533,14 +505,6 @@ def autotune_graph(cfg, params, image_shape, *, graph=None,
             best = autotune_dw_pw_row_chunk(
                 x, dw_p["w"], dw_p["b"], pw_p["w"], pw_p["b"],
                 stride=node.stride, cache=cache, iters=iters)
-        elif node.kind == "conv" and kops.impl() == "pallas":
-            p = params[conv_part(node).name]
-            if not isinstance(p["w"], SparseWeight):
-                continue
-            best = autotune_sparse_conv_block_k(
-                x, p["w"], p["b"], k=node.k, stride=node.stride,
-                relu=node.relu and not node.residual_from,
-                cache=cache, iters=iters)
         else:
             continue
         if verbose:

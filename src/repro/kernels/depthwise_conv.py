@@ -30,14 +30,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.sparse_conv import pad_same_hw, row_window
+from repro.kernels.sparse_conv import (LANES, VMEM_BUDGET_BYTES,
+                                      pad_same_hw)
 
-#: per-core VMEM budget the channel tile is clamped against; half the
-#: hardware's ~16 MB so double-buffered DMAs fit too
-VMEM_BUDGET_BYTES = 8 * 1024 * 1024
 
-#: Mosaic's lane width: a channel tile is a multiple of it, or all of C
-LANES = 128
+def row_window(row_ref, start, wo: int, stride: int):
+    """The (wo, C) window of a resident f32 input row at column
+    ``start`` (static or dynamic), every ``stride``-th column. Mosaic
+    reads strided and dynamically offset windows only from 32-bit
+    refs, so callers stage the row in f32 VMEM first."""
+    if stride == 1:
+        return row_ref[pl.ds(start, wo), :]
+    return row_ref[pl.ds(start, wo, stride=stride), :]
 
 
 def shifted_row_mac(x_ref, row_ref, acc_ref, taps_ky, k: int,

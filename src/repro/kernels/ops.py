@@ -100,7 +100,7 @@ def set_impl(impl: str) -> _ImplGuard:
 
 def set_tuning_cache(cache):
     """Install a :class:`repro.core.tuning.TuningCache` consulted by
-    the dispatchers below for autotuned kernel knobs (block_c, block_k,
+    the dispatchers below for autotuned kernel knobs (block_c,
     row_chunk). Knobs are read at TRACE time, so set the cache before
     compiling. Returns a context-manager guard; ``None`` clears."""
     from repro.core import tuning
@@ -222,18 +222,14 @@ def sparse_conv(x, sw, bias, *, k: int, stride: int = 1,
         sw = sw.dequantized()           # reference path: dequant at entry
     scale = sw.scale                    # (ob, bn) f32 or None
     n, h, w, c = x.shape
-    ob, n_k, bm, bn = sw.vals.shape
+    ob, _, bm, bn = sw.vals.shape
     assert sw.d_in == k * k * c, (sw.d_in, k, c)
     assert c % bm == 0, (c, bm)
     if impl() == "pallas":
         from repro.kernels.sparse_conv import sparse_conv_pallas
-        bk = _knob("sconv", x.shape, x.dtype, "block_k", 1,
-                   k=k, s=stride, b=f"{bm}x{bn}K{n_k}", co=ob * bn)
-        if n_k % max(bk, 1):            # stale cache entry: K changed
-            bk = 1
         return sparse_conv_pallas(x, sw.vals, sw.idx, bias, residual,
                                   scale, k=k, stride=stride, relu=relu,
-                                  block_k=bk, interpret=interpret_mode())
+                                  interpret=interpret_mode())
 
     # XLA path: lax.scan over the K surviving blocks per output column.
     # Each step gathers one shifted (ky, kx) window slice of the

@@ -78,25 +78,25 @@ def _sconv_specs(h, cin, cout, k_keep, stride, *, bm=32, bn=32,
     return specs
 
 
-# (h, cin, cout, k, stride, kept blocks K, block_k): ResNet-50 layers
-# with the paper's 32x32 blocks at 85 % sparsity
+# (h, cin, cout, k, stride, kept blocks K): ResNet-50 layers with the
+# paper's 32x32 blocks at 85 % sparsity
 SPARSE_CONVS = {
-    "s0_c2_3x3_56x56x64": (56, 64, 64, 3, 1, 3, 1),
-    "s1b0_c2_3x3_stride2_56to28": (56, 128, 128, 3, 2, 5, 1),
-    "s2_c1_1x1_14x14_1024to256": (14, 1024, 256, 1, 1, 5, 1),
-    "s3_c2_3x3_7x7x512_block_k2": (7, 512, 512, 3, 1, 22, 2),
+    "s0_c2_3x3_56x56x64": (56, 64, 64, 3, 1, 3),
+    "s1b0_c2_3x3_stride2_56to28": (56, 128, 128, 3, 2, 5),
+    "s2_c1_1x1_14x14_1024to256": (14, 1024, 256, 1, 1, 5),
+    "s3_c2_3x3_7x7x512": (7, 512, 512, 3, 1, 22),
     # the stem's 7x7 stride-2 window at 224 px (one 32-wide block of
     # input channels: the RGB stem itself is too narrow to prune)
-    "stem_7x7_stride2_224": (224, 32, 64, 7, 2, 7, 1),
+    "stem_7x7_stride2_224": (224, 32, 64, 7, 2, 7),
 }
 
 
 @pytest.mark.parametrize("name", sorted(SPARSE_CONVS))
 def test_sparse_conv_compiles_for_v5e(compile_tpu, name):
-    h, cin, cout, k, stride, kk, bk = SPARSE_CONVS[name]
+    h, cin, cout, k, stride, kk = SPARSE_CONVS[name]
     txt = compile_tpu(
         lambda x, v, i, b: sparse_conv_pallas(
-            x, v, i, b, k=k, stride=stride, block_k=bk, interpret=False),
+            x, v, i, b, k=k, stride=stride, interpret=False),
         *_sconv_specs(h, cin, cout, kk, stride))
     assert "tpu_custom_call" in txt
 

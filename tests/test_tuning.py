@@ -12,8 +12,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.configs.base import SparsityConfig
-from repro.core import planner, sparsity as S, tuning
+from repro.core import planner, tuning
 from repro.kernels import depthwise_conv as dwk
 from repro.kernels import ops as kops
 from repro.models import cnn
@@ -219,24 +218,6 @@ def test_autotune_microbatch_knee_and_cap():
 
 # -- knob numerics: schedule changes, never math -----------------------------
 
-def test_sparse_conv_block_k_bitwise():
-    cin, cout, bm, bn, k, h = 8, 16, 4, 8, 3, 8
-    ks = jax.random.split(KEY, 3)
-    w = jax.random.normal(ks[0], (k * k * cin, cout), jnp.float32) / 8
-    x = jax.random.normal(ks[1], (1, h, h, cin), jnp.float32)
-    b = jax.random.normal(ks[2], (cout,), jnp.float32)
-    sw = S.to_block_balanced(w, SparsityConfig(
-        enabled=True, sparsity=0.5, block_m=bm, block_n=bn))
-    n_k = sw.vals.shape[1]
-    from repro.kernels.sparse_conv import sparse_conv_pallas
-    base = sparse_conv_pallas(x, sw.vals, sw.idx, b, k=k, block_k=1,
-                              interpret=True)
-    for bk in [t for t in (2, 3, 4) if n_k % t == 0]:
-        got = sparse_conv_pallas(x, sw.vals, sw.idx, b, k=k, block_k=bk,
-                                 interpret=True)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(base))
-
-
 def test_dw_pw_row_chunk_bitwise():
     from repro.kernels.dw_pw_fused import dw_pw_xla
     ks = jax.random.split(KEY, 4)
@@ -276,8 +257,8 @@ def test_knob_lookup_respects_active_cache():
 
 
 def test_stale_knob_entries_are_ignored(setup):
-    """A cache whose block_c no longer divides C (or block_k no longer
-    divides K) must not crash the dispatcher — the guard falls back."""
+    """A cache whose block_c no longer divides C must not crash the
+    dispatcher — the guard falls back."""
     cfg, params = setup
     x = jax.random.normal(KEY, (1, 16, 16, 12), jnp.float32)
     w = jax.random.normal(KEY, (3, 3, 12), jnp.float32)
